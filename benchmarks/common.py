@@ -59,13 +59,10 @@ def acc(pred, truth) -> float:
 
 def platform_record(dtype=None) -> dict:
     """Machine/runtime metadata every BENCH_*.json carries, so perf
-    trajectories across machines stay comparable."""
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:   # noqa: BLE001 — keep benches alive without devices
-        kind = "unknown"
+    trajectories across machines stay comparable.  A backend that fails
+    to initialize raises: a record never names a device that did not run."""
     return {
-        "device_kind": str(kind),
+        "device_kind": str(jax.devices()[0].device_kind),
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
         "dtype": str(jnp.dtype(dtype).name) if dtype is not None else (

@@ -26,8 +26,34 @@ SMOKE_BENCHES = ("solve", "oos", "build", "sweep", "cg", "dist", "update",
 SMOKE_SCRIPTS = {"roofline": "roofline_report.py"}
 
 
+#: figure/table sections: name -> (title, ``python -m`` arguments).  Each
+#: runs as its own process: the parent never touches JAX (a chip belongs
+#: to one process, so a parent holding it would starve every child), and a
+#: section that flips ``jax_enable_x64`` for its parity gates cannot
+#: re-dtype a later one.
+SECTIONS = {
+    "fig3": ("fig3_stability (error-vs-sigma variance bands)",
+             ["benchmarks.fig3_stability"]),
+    "fig4": ("fig4_partitioning (+Table 2 overhead)",
+             ["benchmarks.fig4_partitioning"]),
+    "fig56": ("fig5/6 performance vs r / time / memory",
+              ["benchmarks.fig56_perf_vs_r"]),
+    "fig7": ("fig7 n-vs-r trade-off", ["benchmarks.fig7_n_vs_r"]),
+    "fig8": ("fig8 kernel-PCA alignment", ["benchmarks.fig8_kpca"]),
+    "sweep": ("sweep engine (σ×λ grid amortization, BENCH_sweep.json)",
+              ["benchmarks.bench_sweep", "--smoke",
+               "--out", "BENCH_sweep.json"]),
+    "cg": ("iterative solvers (exact-kernel PCG, BENCH_cg.json)",
+           ["benchmarks.bench_cg", "--smoke", "--out", "BENCH_cg.json"]),
+    "cost": ("cost scaling of Alg 1/2/3 (paper §4.5)",
+             ["benchmarks.cost_scaling"]),
+    "roofline": ("roofline table (dry-run artifacts + BENCH_*.json)",
+                 ["benchmarks.roofline_report", "--bench-dir", "."]),
+}
+
+
 def _section(name):
-    print(f"\n==== {name} " + "=" * max(0, 60 - len(name)))
+    print(f"\n==== {name} " + "=" * max(0, 60 - len(name)), flush=True)
 
 
 def run_smoke(name: str) -> int:
@@ -52,6 +78,9 @@ def run_smoke(name: str) -> int:
 
 
 def main() -> None:
+    import pathlib
+    import subprocess
+
     argv = sys.argv[1:]
     if argv and argv[0] == "--smoke":
         if len(argv) != 2:
@@ -59,102 +88,25 @@ def main() -> None:
             raise SystemExit(2)
         raise SystemExit(run_smoke(argv[1]))
     which = set(argv)
+    unknown = which - set(SECTIONS)
+    if unknown:
+        print(f"unknown section(s) {sorted(unknown)}; pick from "
+              f"{', '.join(SECTIONS)}", file=sys.stderr)
+        raise SystemExit(2)
 
-    def want(name):
-        return not which or name in which
-
+    root = pathlib.Path(__file__).resolve().parent.parent
     summary = []
     failed = 0
-
-    if want("fig3"):
-        _section("fig3_stability (error-vs-sigma variance bands)")
-        from benchmarks import fig3_stability
-
+    for name, (title, args) in SECTIONS.items():
+        if which and name not in which:
+            continue
+        _section(title)
         t0 = time.perf_counter()
-        fig3_stability.run()
-        summary.append(("fig3_stability", time.perf_counter() - t0))
-
-    if want("fig4"):
-        _section("fig4_partitioning (+Table 2 overhead)")
-        from benchmarks import fig4_partitioning
-
-        t0 = time.perf_counter()
-        fig4_partitioning.run()
-        summary.append(("fig4_partitioning", time.perf_counter() - t0))
-
-    if want("fig56"):
-        _section("fig5/6 performance vs r / time / memory")
-        from benchmarks import fig56_perf_vs_r
-
-        t0 = time.perf_counter()
-        fig56_perf_vs_r.run()
-        summary.append(("fig56_perf_vs_r", time.perf_counter() - t0))
-
-    if want("fig7"):
-        _section("fig7 n-vs-r trade-off")
-        from benchmarks import fig7_n_vs_r
-
-        t0 = time.perf_counter()
-        fig7_n_vs_r.run()
-        summary.append(("fig7_n_vs_r", time.perf_counter() - t0))
-
-    if want("fig8"):
-        _section("fig8 kernel-PCA alignment")
-        from benchmarks import fig8_kpca
-
-        t0 = time.perf_counter()
-        fig8_kpca.run()
-        summary.append(("fig8_kpca", time.perf_counter() - t0))
-
-    if want("sweep"):
-        _section("sweep engine (σ×λ grid amortization, BENCH_sweep.json)")
-        # subprocess, not import: bench_sweep flips jax_enable_x64 globally
-        # for its parity gates, which would silently re-dtype every later
-        # section (cost/roofline) if run in-process
-        import pathlib
-        import subprocess
-
-        t0 = time.perf_counter()
-        rc = subprocess.run(
-            [sys.executable,
-             str(pathlib.Path(__file__).parent / "bench_sweep.py"),
-             "--smoke", "--out", "BENCH_sweep.json"]).returncode
-        summary.append(("bench_sweep_smoke", time.perf_counter() - t0))
+        rc = subprocess.run([sys.executable, "-m", *args],
+                            cwd=root).returncode
+        summary.append((args[0].split(".")[-1], time.perf_counter() - t0))
         if rc:
-            failed = rc           # parity-gate miss must not exit 0
-
-    if want("cg"):
-        _section("iterative solvers (exact-kernel PCG, BENCH_cg.json)")
-        # subprocess, not import: bench_cg flips jax_enable_x64 globally
-        # for its dense-parity gate, which would re-dtype later sections
-        import pathlib
-        import subprocess
-
-        t0 = time.perf_counter()
-        rc = subprocess.run(
-            [sys.executable,
-             str(pathlib.Path(__file__).parent / "bench_cg.py"),
-             "--smoke", "--out", "BENCH_cg.json"]).returncode
-        summary.append(("bench_cg_smoke", time.perf_counter() - t0))
-        if rc:
-            failed = rc           # parity/ratio-gate miss must not exit 0
-
-    if want("cost"):
-        _section("cost scaling of Alg 1/2/3 (paper §4.5)")
-        from benchmarks import cost_scaling
-
-        t0 = time.perf_counter()
-        cost_scaling.run()
-        summary.append(("cost_scaling", time.perf_counter() - t0))
-
-    if want("roofline"):
-        _section("roofline table (dry-run artifacts + BENCH_*.json)")
-        from benchmarks import roofline_report
-
-        t0 = time.perf_counter()
-        roofline_report.run()
-        roofline_report.bench_table(".")
-        summary.append(("roofline_report", time.perf_counter() - t0))
+            failed = rc           # a section's gate miss must not exit 0
 
     _section("summary")
     print("name,us_per_call,derived")

@@ -350,11 +350,6 @@ def _transfer_ops(landmarks: tuple, sigma_li: list, kernel: BaseKernel,
     return tuple(w)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("levels", "rank", "method", "shared_landmarks", "kernel",
-                     "config", "policy", "rank_budget"),
-)
 def build_hck(
     x: Array,
     *,
@@ -407,24 +402,53 @@ def build_hck(
     Returns
     -------
     :class:`HCKFactors` with all per-level factor stacks.
-    """
-    from repro.landmarks.policy import get_policy
 
-    config = config if config is not None else DEFAULT_CONFIG
-    policy = get_policy(policy)
-    n, d = x.shape
+    The partition runs as its own compiled program, :func:`build_partition`
+    on ``x`` as given, and the factors as a second one.  The tree is a
+    discrete function of rounded projections: a program that fused the
+    projections differently could round a point at a node's median to the
+    other side.  Keeping the partition program apart lets
+    :func:`repro.launch.dist_hck.dist_build_hck` run the very same one, so
+    both builds get the same tree on any backend.
+    """
+    n = x.shape[0]
     n_leaves = 1 << levels
     if n % n_leaves != 0:
         raise ValueError(f"n={n} not divisible by 2**levels={n_leaves}")
-    n0 = n // n_leaves
-    if rank > n0:
-        raise ValueError(f"rank {rank} exceeds leaf size {n0} (paper §4.4)")
+    if rank > n // n_leaves:
+        raise ValueError(f"rank {rank} exceeds leaf size {n // n_leaves} "
+                         "(paper §4.4)")
     if rank_budget is not None and levels == 0:
         raise ValueError("rank_budget requires levels >= 1 "
                          "(a 0-level build has no low-rank factors)")
 
     kpart, key = jax.random.split(key)
     x_sorted, tree = build_partition(x, levels, kpart, method=method)
+    return _build_on_tree(x_sorted, tree, key, levels=levels, rank=rank,
+                          kernel=kernel, shared_landmarks=shared_landmarks,
+                          config=config, policy=policy,
+                          rank_budget=rank_budget)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("levels", "rank", "shared_landmarks", "kernel", "config",
+                     "policy", "rank_budget"),
+)
+def _build_on_tree(x_sorted: Array, tree: PartitionTree, key: Array, *,
+                   levels: int, rank: int, kernel: BaseKernel,
+                   shared_landmarks: bool, config: SolveConfig | None,
+                   policy, rank_budget: int | None) -> HCKFactors:
+    """:func:`build_hck` after the partition: landmarks and every factor on
+    the tree-ordered points ``x_sorted``, with ``key`` the key left after
+    the partition subkey is split off."""
+    from repro.landmarks.policy import get_policy
+
+    config = config if config is not None else DEFAULT_CONFIG
+    policy = get_policy(policy)
+    n, d = x_sorted.shape
+    n_leaves = 1 << levels
+    n0 = n // n_leaves
 
     # --- landmarks: per-node subsample under the selection policy --------
     landmarks = _draw_level_landmarks(
@@ -455,7 +479,7 @@ def build_hck(
     adiag, _ = _stage_build_gram(leaves, kernel, config, want_chol=False)
     if levels == 0:
         return HCKFactors(x_sorted, tree, (), (), (), (),
-                          jnp.zeros((1, n0, 0), x.dtype), adiag)
+                          jnp.zeros((1, n0, 0), x_sorted.dtype), adiag)
 
     # U_i = K(X_i, Xl_p) inv(K(Xl_p, Xl_p)); parent of leaf i is i//2.
     # Sibling leaves share their parent's landmarks and Linv, so the cross

@@ -132,13 +132,13 @@ def reset_db() -> None:
 
 @functools.lru_cache(maxsize=None)
 def device_kind() -> str:
-    """Fine-grained device kind of device 0 (sanitized for DB keys)."""
+    """Fine-grained device kind of device 0 (sanitized for DB keys).
+
+    A backend that fails to initialize raises rather than reading as cpu.
+    """
     import jax
 
-    try:
-        kind = jax.devices()[0].device_kind
-    except Exception:   # noqa: BLE001 — backend init failure -> cpu
-        kind = "cpu"
+    kind = jax.devices()[0].device_kind
     return str(kind).strip().replace(" ", "_").replace("|", "_") or "cpu"
 
 

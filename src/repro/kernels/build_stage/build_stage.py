@@ -72,26 +72,33 @@ def _pairwise(x: Array, y: Array, *, l1: bool, epilogue, acc) -> Array:
 def _cholesky_in_vmem(a: Array, m: int, acc) -> Array:
     """Right-looking Cholesky of an SPD (m, m) tile via one-hot updates.
 
-    Column ``j`` of the factor is extracted with a one-hot contraction and
-    the trailing Schur complement is updated with a masked outer product —
-    no dynamic slicing, so the loop lowers on TPU and in interpret mode
-    alike.  O(m^3/3) flops over an m-step sequential loop (the tile stays
-    in VMEM throughout).
+    Column ``j`` of the factor is picked out with a one-hot masked lane
+    reduction and the trailing Schur complement is updated with a masked
+    outer product — no dynamic slicing and no vector-shaped dot (Mosaic
+    refuses a contraction with a 1-D operand), so the loop lowers on TPU
+    and in interpret mode alike.  Every operand stays 2-D: columns are
+    (m, 1), rows (1, m).  O(m^3/3) flops over an m-step sequential loop
+    (the tile stays in VMEM throughout).
     """
-    rows = jax.lax.iota(jnp.int32, m)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+    eye = (rows == cols).astype(acc)
 
     def body(j, a):
-        ej = (rows == j).astype(acc)                       # one-hot (m,)
+        ej = (cols == j).astype(acc)                       # one-hot (1, m)
+        acol = jnp.sum(a * ej, axis=1, keepdims=True)      # column j (m, 1)
         # no pivot clamp: a singular/indefinite block must yield NaN, the
         # same loud failure mode as the xla backend's jnp.linalg.cholesky
-        pivot = jnp.sqrt(ej @ a @ ej)
-        col = jnp.where(rows >= j, (a @ ej) / pivot, 0.0)  # column j of L
+        pivot = jnp.sqrt(jnp.sum(jnp.where(rows == j, acol, 0.0),
+                                 axis=0, keepdims=True))   # (1, 1)
+        col = jnp.where(rows >= j, acol / pivot, 0.0)      # column j of L
         tail = jnp.where(rows > j, col, 0.0)
-        a = a - tail[:, None] * tail[None, :]              # Schur update
-        return a * (1.0 - ej)[None, :] + col[:, None] * ej[None, :]
+        tail_row = jnp.sum(tail * eye, axis=0, keepdims=True)   # (1, m)
+        a = a - tail * tail_row                            # Schur update
+        return a * (1.0 - ej) + col * ej
 
     a = jax.lax.fori_loop(0, m, body, a)
-    return a * (rows[:, None] >= rows[None, :]).astype(acc)
+    return a * (rows >= cols).astype(acc)
 
 
 def _gram_chol_body(pts_ref, gram_ref, chol_ref, *, l1: bool, epilogue,

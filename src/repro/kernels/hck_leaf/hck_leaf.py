@@ -164,18 +164,25 @@ def _tri_inv_in_vmem(lo: Array, m: int, acc) -> Array:
     Row ``i`` of ``X = lo^{-1}`` solves ``lo[i, i] X[i, :] = e_i -
     lo[i, :] X`` where the contraction only touches the already-computed
     rows < i.  Like the Cholesky loop, every step is a one-hot masked
-    rank-1 update — no dynamic slicing, so the same body lowers under
-    Mosaic and interpret mode.  O(m^3/2) flops over an m-step loop.
+    rank-1 update — no dynamic slicing and no vector-shaped dot (Mosaic
+    refuses a contraction with a 1-D operand): rows are picked out with
+    masked sublane reductions and every operand stays 2-D, so the same
+    body lowers under Mosaic and interpret mode.  O(m^3/2) flops over an
+    m-step loop.
     """
-    rows = jax.lax.iota(jnp.int32, m)
+    rows = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+    eye = (rows == cols).astype(acc)
 
     def body(i, x):
-        ei = (rows == i).astype(acc)                       # one-hot (m,)
-        lrow = ei @ lo                                     # row i of lo
-        s = lrow @ x                                       # uses rows < i
-        pivot = lrow @ ei                                  # lo[i, i]
-        newrow = (ei - s) / pivot
-        return x + ei[:, None] * newrow[None, :]
+        ei_col = (rows == i).astype(acc)                   # one-hot (m, 1)
+        ei_row = (cols == i).astype(acc)                   # one-hot (1, m)
+        lrow = jnp.sum(lo * ei_col, axis=0, keepdims=True)     # row i (1, m)
+        lcol = jnp.sum(lrow * eye, axis=1, keepdims=True)      # as (m, 1)
+        s = jnp.sum(lcol * x, axis=0, keepdims=True)       # uses rows < i
+        pivot = jnp.sum(lrow * ei_row, axis=1, keepdims=True)  # lo[i, i]
+        newrow = (ei_row - s) / pivot
+        return x + ei_col * newrow
 
     return jax.lax.fori_loop(0, m, body, jnp.zeros((m, m), acc))
 

@@ -40,23 +40,37 @@ def _acc_dtype(*arrays: Array):
     return jnp.float32
 
 
-def _contract_body(pts_ref, w_ref, q_ref, o_ref, *, l1: bool, epilogue, acc):
+def _bdot(a: Array, b: Array, acc) -> Array:
+    """Per-query a @ b^T: (bq, s, c), (bq, t, c) -> (bq, s, t).
+
+    Both operands keep a free dim, so Mosaic lowers it as a batched MXU
+    matmul (it refuses a contraction whose operand has no free dim).  Full
+    f32 contract precision: the distance identity below cancels large
+    terms and a single bf16 pass would lose the kernel values.
+    """
+    return jax.lax.dot_general(
+        a, b, (((2,), (2,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=acc)
+
+
+def _contract_body(pts_ref, wt_ref, q_ref, o_ref, *, l1: bool, epilogue, acc):
     pts = pts_ref[...]                                 # (bq, m, d)
-    w = w_ref[...]                                     # (bq, m, k)
-    x = q_ref[...]                                     # (bq, d)
+    wt = wt_ref[...]                                   # (bq, k, m)
+    x = q_ref[...]                                     # (bq, 1, d)
+    ones = jnp.ones(x.shape, acc)
     if l1:
-        dist = jnp.sum(jnp.abs(pts - x[:, None, :]), axis=-1)
+        # the all-ones contraction sums over d AND lays the result out
+        # along lanes, (bq, 1, m), ready for the weight contraction
+        dist = _bdot(ones, jnp.abs(pts - x), acc)
     else:
         # ||p - x||^2 = ||p||^2 + ||x||^2 - 2 p.x ; p.x is a batched MXU
         # contraction over the feature dim
-        xy = jax.lax.dot_general(
-            pts, x, (((2,), (1,)), ((0,), (0,))), preferred_element_type=acc)
         dist = jnp.maximum(
-            jnp.sum(pts * pts, axis=-1)
-            + jnp.sum(x * x, axis=-1)[:, None] - 2.0 * xy, 0.0)
-    kv = epilogue(dist).astype(acc)                    # (bq, m)
-    o_ref[...] = jax.lax.dot_general(
-        kv, w, (((1,), (1,)), ((0,), (0,))), preferred_element_type=acc)
+            _bdot(ones, pts * pts, acc)
+            + jnp.sum(x * x, axis=-1, keepdims=True)
+            - 2.0 * _bdot(x, pts, acc), 0.0)
+    kv = epilogue(dist).astype(acc)                    # (bq, 1, m)
+    o_ref[...] = _bdot(kv, wt, acc)                    # (bq, 1, k)
 
 
 @functools.partial(jax.jit, static_argnames=("name", "sigma", "bq",
@@ -67,7 +81,13 @@ def oos_contract_kernel(
     interpret: bool = True,
 ) -> Array:
     """(q, m, d), (q, m, k), (q, d) -> z (q, k); q must divide ``bq``
-    (use ops.oos_contract for the padded general entry point)."""
+    (use ops.oos_contract for the padded general entry point).
+
+    The weights go in transposed, (q, k, m), and the queries as (q, 1, d),
+    so that the kernel values, the weights and the queries all keep the
+    contraction dim on lanes: no in-kernel relayout, and a one-column
+    weight block costs a sublane tile, not a lane tile, of VMEM.
+    """
     if name not in SUPPORTED:
         raise ValueError(f"{name!r} not in {SUPPORTED}")
     q, m, d = points.shape
@@ -77,15 +97,16 @@ def oos_contract_kernel(
     body = functools.partial(
         _contract_body, l1=(name == "laplace"),
         epilogue=kernel_epilogue(name, sigma), acc=acc)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         body,
         grid=(q // bq,),
         in_specs=[
             pl.BlockSpec((bq, m, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bq, m, k), lambda i: (i, 0, 0)),
-            pl.BlockSpec((bq, d), lambda i: (i, 0)),
+            pl.BlockSpec((bq, k, m), lambda i: (i, 0, 0)),
+            pl.BlockSpec((bq, 1, d), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((bq, k), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((q, k), acc),
+        out_specs=pl.BlockSpec((bq, 1, k), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((q, 1, k), acc),
         interpret=interpret,
-    )(points, weights, queries)
+    )(points, jnp.swapaxes(weights, 1, 2), queries[:, None, :])
+    return out[:, 0, :]

@@ -70,6 +70,12 @@ BUILD_STAGES = ("build_gram", "build_cross",
 #: loops; see repro.landmarks and repro.kernels.policy_stage).
 POLICY_STAGES = ("policy_dist",)
 
+#: stages whose every array operand is stacked along one leading (leaf,
+#: node or query) axis of independent rows: a launch can run on each
+#: device over the rows it owns (see :func:`get_impl`).
+ROW_STAGES = ("leaf_matvec", "leaf_solve", "leaf_factor", "leaf_update",
+              "leaf_project") + OOS_STAGES + BUILD_STAGES + POLICY_STAGES
+
 
 # ---------------------------------------------------------------------------
 # SolveConfig — the one shared knob object
@@ -77,13 +83,14 @@ POLICY_STAGES = ("policy_dist",)
 
 @functools.lru_cache(maxsize=None)
 def accelerator_present() -> bool:
-    """True when the default jax backend is a real accelerator (not CPU)."""
+    """True when the default jax backend is a real accelerator (not CPU).
+
+    A backend that fails to initialize raises: a broken accelerator must
+    not quietly turn every Pallas stage into CPU interpret mode.
+    """
     import jax
 
-    try:
-        return jax.default_backend() not in ("cpu",)
-    except Exception:   # noqa: BLE001 — backend init failure == no device
-        return False
+    return jax.default_backend() != "cpu"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -335,7 +342,14 @@ def tile_config(stage: str, *, n0: int, r: int, k: int, d: int = 0,
 
     if stage in OOS_STAGES:
         def usage(bq: int) -> int:
-            per_query = n0 * (d + k + 1) + d + k   # points + weights + kv + io
+            # VMEM tiles as laid out (sublanes padded to 8, lanes to 128):
+            # points (n0, d) + transposed weights (k, n0) + query row
+            # (1, d) + output row (1, k)
+            def tile(rows: int, cols: int) -> int:
+                return -(-rows // 8) * 8 * (-(-cols // 128) * 128)
+
+            per_query = (tile(n0, d) + tile(k, n0) + tile(1, d)
+                         + tile(1, k))
             return bq * per_query * itemsize
 
         bq = leaf_block if leaf_block is not None else 128
@@ -390,14 +404,46 @@ def register(stage: str, backend: str):
 
 
 def get_impl(stage: str, backend: str) -> Callable:
-    """Implementation registered for (stage, backend); KeyError if none."""
+    """Implementation registered for (stage, backend); KeyError if none.
+
+    Pallas launches of the :data:`ROW_STAGES` go through
+    :func:`_launch_on_mesh`, so they also run where GSPMD partitions the
+    surrounding program over a context mesh (``jax.set_mesh``).
+    """
     try:
-        return _REGISTRY[(stage, backend)]
+        impl = _REGISTRY[(stage, backend)]
     except KeyError:
         have = sorted(k for k in _REGISTRY if k[0] == stage)
         raise KeyError(
             f"no implementation registered for stage={stage!r} "
             f"backend={backend!r}; registered: {have}") from None
+    if backend == "pallas" and stage in ROW_STAGES:
+        return functools.partial(_launch_on_mesh, impl)
+    return impl
+
+
+def _launch_on_mesh(impl: Callable, *args, **kwargs):
+    """Run one row-stage launch per device of the context mesh.
+
+    GSPMD cannot partition a Mosaic kernel: on a TPU mesh a Pallas call
+    must sit in a ``shard_map`` body.  Under a context mesh with automatic
+    axes the launch is wrapped in one, its rows split over every mesh axis
+    when the row count divides the mesh (otherwise every device runs all
+    rows, as for the replicated top of the tree).  With no context mesh,
+    or inside a ``shard_map`` body already, the launch runs as it is.
+    """
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1 or mesh.manual_axes:
+        return impl(*args, **kwargs)
+    spec = P(mesh.axis_names) if args[0].shape[0] % mesh.size == 0 else P()
+    # check_vma=False: Pallas declares its outputs without the
+    # varying-mesh-axes type that check_vma requires
+    return jax.shard_map(functools.partial(impl, **kwargs),
+                         in_specs=(spec,) * len(args), out_specs=spec,
+                         check_vma=False)(*args)
 
 
 def registered(stage: str | None = None) -> list[tuple[str, str]]:

@@ -25,14 +25,12 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.core import hmatrix
-from repro.core.hck import (HCKFactors, _stage_build_cross, _stage_build_gram,
-                            build_hck, landmark_indices, leaf_stage_factors,
-                            sigma_linv)
+from repro.core.hck import (HCKFactors, _middle_factors, _transfer_ops,
+                            build_hck, landmark_indices, leaf_stage_factors)
 from repro.core.kernels_fn import KERNEL_METRIC, BaseKernel
 from repro.kernels.registry import (DEFAULT_CONFIG, SolveConfig, get_impl,
                                     resolve_backend)
@@ -319,150 +317,12 @@ def shard_by_subtree(tree_like, mesh: Mesh, axis: str = "dev"):
     return jax.tree.map(put, tree_like)
 
 
-@jax.jit
-def _level_projections(xp: Array, dmat: Array) -> Array:
-    """(n, d) permuted points x (B, d) node directions -> (B, n/B)."""
-    bsz = dmat.shape[0]
-    blocks = xp.reshape(bsz, xp.shape[0] // bsz, xp.shape[1])
-    return jnp.einsum("bmd,bd->bm", blocks, dmat)
-
-
-def dist_partition(x: Array, levels: int, key: Array, mesh: Mesh, *,
-                   method: str = "rp", axis: str = "dev"):
-    """Mesh-parallel balanced partition (distributed ``build_partition``).
-
-    Projections run on the mesh: the permuted points are committed
-    row-sharded over ``axis`` and each level's (B, m, d) x (B, d)
-    contraction partitions under GSPMD with zero communication (the
-    contraction axis d is unsharded).  The median split — stable argsort
-    + threshold per node — runs on the host exactly as
-    :func:`repro.data.pipeline.stream_partition` does.  Both pieces are
-    pinned bit-identical to :func:`repro.core.partition.build_partition`
-    (same :func:`~repro.core.partition.rp_directions` key tree, same
-    stable sort, same threshold arithmetic), so the distributed build's
-    factor-parity gates hold all the way down to the permutation.
-
-    Returns ``(x_sorted, tree)`` with ``x_sorted`` committed row-sharded
-    to the mesh.
-    """
-    from repro.core.partition import PartitionTree, rp_directions
-
-    if method != "rp":
-        raise NotImplementedError(
-            f"dist_partition supports method='rp' only, got {method!r}")
-    n, d = x.shape
-    if n % (1 << levels) != 0:
-        raise ValueError(f"n={n} not divisible by 2**levels={1 << levels}")
-    if n % mesh.size != 0:
-        raise ValueError(f"n={n} not divisible by mesh size {mesh.size}")
-    row_sh = NamedSharding(mesh, P(axis))
-    x_host = np.asarray(x)
-    dtype = jnp.asarray(x[:1]).dtype
-    perm = np.arange(n, dtype=np.int64)
-    dirs, thrs = [], []
-    for lvl in range(levels):
-        key, sub = jax.random.split(key)
-        bsz, m = 1 << lvl, n >> lvl
-        dmat = rp_directions(sub, bsz, d, dtype)
-        xp = jax.device_put(x_host[perm].astype(dtype), row_sh)
-        proj = np.asarray(_level_projections(xp, dmat))
-        thr_lvl = np.empty((bsz,), dtype=proj.dtype)
-        for b in range(bsz):
-            order = np.argsort(proj[b], kind="stable")
-            sp = proj[b][order]
-            thr_lvl[b] = thr_lvl.dtype.type(0.5) * (sp[m // 2 - 1] + sp[m // 2])
-            perm[b * m:(b + 1) * m] = perm[b * m:(b + 1) * m][order]
-        dirs.append(dmat)
-        thrs.append(jnp.asarray(thr_lvl))
-    x_sorted = jax.device_put(x_host[perm].astype(dtype), row_sh)
-    tree = PartitionTree(jnp.asarray(perm, dtype=jnp.int32),
-                         tuple(dirs), tuple(thrs))
-    return x_sorted, tree
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_gram_fn(mesh: Mesh, axis: str, kernel: BaseKernel,
-                     config: SolveConfig, want_chol: bool):
-    """jit(shard_map) wrapper of the ``build_gram`` stage, cached per
-    (mesh, kernel, config) so repeated builds reuse one executable.
-    Returns gram only (``want_chol=False``) or (gram, chol, Linv)."""
-    def body(blocks):
-        gram, chol = _stage_build_gram(blocks, kernel, config,
-                                       want_chol=want_chol)
-        if not want_chol:
-            return gram
-        return gram, chol, sigma_linv(chol)
-
-    spec = P(axis)
-    out = (spec, spec, spec) if want_chol else spec
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
-                             out_specs=out))
-
-
-@functools.lru_cache(maxsize=None)
-def _sharded_cross_fn(mesh: Mesh, axis: str, kernel: BaseKernel,
-                      config: SolveConfig):
-    """jit(shard_map) wrapper of the ``build_cross`` stage at CHILD
-    granularity: parent landmark/Linv stacks arrive pre-repeated per
-    child, so a sibling pair never straddles a device boundary and each
-    device's launch touches only rows it owns."""
-    def body(blocks, lm_parent, linv_parent):
-        return _stage_build_cross(blocks, lm_parent, linv_parent, kernel,
-                                  config)
-
-    spec = P(axis)
-    return jax.jit(shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                             out_specs=spec))
-
-
-def _dist_middle_factors(landmarks: tuple, kernel: BaseKernel,
-                         config: SolveConfig, mesh: Mesh, axis: str):
-    """Per-level Sigma / Cholesky / Linv with the top-tree split.
-
-    Levels with fewer than P nodes are tiny ((<P) x r x r) and are
-    computed replicated — the distributed analogue of
-    :func:`build_top_factors`' replicated top tree — while levels with
-    at least one node per device run node-sharded under ``shard_map``.
-    Stage rows are independent, so both placements produce the values of
-    :func:`repro.core.hck._middle_factors` exactly.
-    """
-    p = mesh.size
-    sigma, sigma_cho, sigma_li = [], [], []
-    for lm in landmarks:
-        if lm.shape[0] < p:
-            s, c = _stage_build_gram(lm, kernel, config)
-            li = sigma_linv(c)
-        else:
-            s, c, li = _sharded_gram_fn(mesh, axis, kernel, config, True)(lm)
-        sigma.append(s)
-        sigma_cho.append(c)
-        sigma_li.append(li)
-    return tuple(sigma), tuple(sigma_cho), sigma_li
-
-
-def _dist_transfer_ops(landmarks: tuple, sigma_li: list, kernel: BaseKernel,
-                       config: SolveConfig, mesh: Mesh, axis: str) -> tuple:
-    """W factors at levels 1..L-1, mesh-parallel.
-
-    Replicated (top) levels reuse ``build_hck``'s paired-sibling launch;
-    node-sharded levels run at child granularity with parent stacks
-    repeated per child (the streaming engine's leaf layout).  Each stage
-    row is independent, so the two granularities are value-identical.
-    """
-    p = mesh.size
-    rank, d = landmarks[0].shape[1], landmarks[0].shape[2]
-    w = []
-    for lvl in range(1, len(landmarks)):
-        if (1 << lvl) < p:
-            paired = landmarks[lvl].reshape(1 << (lvl - 1), 2 * rank, d)
-            w.append(_stage_build_cross(
-                paired, landmarks[lvl - 1], sigma_li[lvl - 1], kernel,
-                config).reshape(1 << lvl, rank, rank))
-        else:
-            w.append(_sharded_cross_fn(mesh, axis, kernel, config)(
-                landmarks[lvl], jnp.repeat(landmarks[lvl - 1], 2, axis=0),
-                jnp.repeat(sigma_li[lvl - 1], 2, axis=0)))
-    return tuple(w)
+# build_hck's stage groups, each one jitted program: fused as build_hck
+# fuses them, so the mesh build rounds as the single-host build does
+# (op-by-op dispatch would not), and partitioned over the context mesh
+_middle_factors_jit = jax.jit(_middle_factors, static_argnums=(1, 2))
+_leaf_stage_factors_jit = jax.jit(leaf_stage_factors, static_argnums=(3, 4))
+_transfer_ops_jit = jax.jit(_transfer_ops, static_argnums=(2, 3))
 
 
 def dist_build_hck(x: Array, *, levels: int, rank: int, key: Array,
@@ -475,14 +335,20 @@ def dist_build_hck(x: Array, *, levels: int, rank: int, key: Array,
     Same key tree (partition subkey first, then one landmark subkey per
     level) and same registry stages as the single-host batched engine,
     so the returned factors MATCH ``build_hck`` on the same key —
-    ``tests/test_dist_build.py`` pins the parity at 1e-12 in f64.  The
-    layout is the subtree ownership of this module's header: device p
-    owns the contiguous leaf range whose root-path prefix is p, levels
-    with < P nodes are replicated from one gather, deeper levels are
-    node-sharded, and every ``build_gram`` / ``build_cross`` launch runs
-    under ``shard_map`` on local rows only (zero per-stage
-    communication; the U/W stages use child granularity with parents
-    repeated so sibling pairs never straddle devices).
+    ``tests/test_dist_build.py`` pins the parity at 1e-12 in f64 on the
+    CPU.  The partition is ``build_hck``'s own compiled program,
+    :func:`~repro.core.partition.build_partition` on ``x`` as given (the
+    tree is a discrete function of rounded projections, so only the same
+    program is sure to give the same tree on every backend; pass ``x`` on
+    one device, as ``build_hck`` gets it).  The factors follow the
+    subtree ownership of this module's header: device p owns the
+    contiguous leaf range whose root-path prefix is p, levels with < P
+    nodes are replicated from one gather, deeper levels are node-sharded,
+    and the stage launches are :func:`build_hck`'s own, run with ``mesh``
+    as the context mesh: XLA stages partition under GSPMD and Pallas
+    stages run per device on the rows each owns (the leaf U stage uses
+    leaf granularity with parents repeated, so sibling pairs never
+    straddle devices).
 
     ``policy`` / ``rank_budget`` mirror :func:`~repro.core.hck.build_hck`:
     the uniform policy stays INDEX-bitwise with the single-host build
@@ -498,15 +364,15 @@ def dist_build_hck(x: Array, *, levels: int, rank: int, key: Array,
     :func:`shard_by_subtree`.
     """
     from repro.core.hck import _apply_rank_masks, _mask_transfer_ops
+    from repro.core.partition import build_partition
     from repro.landmarks.policy import (UniformPolicy, get_policy,
                                         select_indices)
 
     config = config if config is not None else DEFAULT_CONFIG
     policy = get_policy(policy)
-    metric = KERNEL_METRIC.get(kernel.name, "l2")
     p = mesh.size
     t = device_level(p)
-    n, d = x.shape
+    n = x.shape[0]
     n_leaves = 1 << levels
     if levels < max(t, 1):
         raise ValueError(
@@ -514,63 +380,66 @@ def dist_build_hck(x: Array, *, levels: int, rank: int, key: Array,
             f"log2(P)={t} so each device owns at least one leaf")
     if n % n_leaves != 0:
         raise ValueError(f"n={n} not divisible by 2**levels={n_leaves}")
-    n0 = n // n_leaves
-    if rank > n0:
-        raise ValueError(f"rank {rank} exceeds leaf size {n0} (paper §4.4)")
+    if rank > n // n_leaves:
+        raise ValueError(f"rank {rank} exceeds leaf size {n // n_leaves} "
+                         "(paper §4.4)")
 
     kpart, key = jax.random.split(key)
-    x_sorted, tree = dist_partition(x, levels, kpart, mesh, method=method,
-                                    axis=axis)
+    x_sorted, tree = build_partition(x, levels, kpart, method=method)
     xs_host = np.asarray(x_sorted)
-
+    metric = KERNEL_METRIC.get(kernel.name, "l2")
+    n, d = x.shape
+    n0 = n // n_leaves
     node_sh = NamedSharding(mesh, P(axis))
     rep_sh = NamedSharding(mesh, P())
+    x_sorted = jax.device_put(x_sorted, node_sh)
 
-    # landmarks: engine-identical indices (same per-level subkeys as
-    # build_hck); top-tree stacks (< P nodes) replicate on every device
-    # — the one-all_gather "replicate the tree top" move — and deeper
-    # stacks are committed node-sharded.
-    landmarks = []
-    for lvl in range(levels):
-        key, sub = jax.random.split(key)
-        bsz, m = 1 << lvl, n >> lvl
-        if isinstance(policy, UniformPolicy):
-            idx = np.asarray(landmark_indices(sub, bsz, m, rank))
-        else:
-            blocks = jnp.asarray(xs_host).reshape(bsz, m, d)
-            idx = np.asarray(select_indices(policy, sub, blocks, rank,
-                                            metric=metric, config=config))
-        rows = (np.arange(bsz)[:, None] * m + idx).reshape(-1)
-        lm = jnp.asarray(xs_host[rows]).reshape(bsz, rank, d)
-        landmarks.append(jax.device_put(lm, node_sh if bsz >= p else rep_sh))
-    landmarks = tuple(landmarks)
+    with jax.set_mesh(mesh):
+        # landmarks: engine-identical indices (same per-level subkeys as
+        # build_hck); top-tree stacks (< P nodes) replicate on every device
+        # — the one-all_gather "replicate the tree top" move — and deeper
+        # stacks are committed node-sharded.
+        landmarks = []
+        for lvl in range(levels):
+            key, sub = jax.random.split(key)
+            bsz, m = 1 << lvl, n >> lvl
+            if isinstance(policy, UniformPolicy):
+                idx = np.asarray(landmark_indices(sub, bsz, m, rank))
+            else:
+                blocks = jnp.asarray(xs_host).reshape(bsz, m, d)
+                idx = np.asarray(select_indices(policy, sub, blocks, rank,
+                                                metric=metric, config=config))
+            rows = (np.arange(bsz)[:, None] * m + idx).reshape(-1)
+            lm = jnp.asarray(xs_host[rows]).reshape(bsz, rank, d)
+            landmarks.append(
+                jax.device_put(lm, node_sh if bsz >= p else rep_sh))
+        landmarks = tuple(landmarks)
 
-    sigma, sigma_cho, sigma_li = _dist_middle_factors(
-        landmarks, kernel, config, mesh, axis)
+        sigma, sigma_cho, sigma_li = _middle_factors_jit(landmarks, kernel,
+                                                         config)
 
-    rank_mask = None
-    if rank_budget is not None:
-        from repro.landmarks.budget import allocate_rank_masks
+        rank_mask = None
+        if rank_budget is not None:
+            from repro.landmarks.budget import allocate_rank_masks
 
-        rank_mask = allocate_rank_masks(sigma, rank_budget, rank)
-        sigma, sigma_cho, sigma_li = _apply_rank_masks(
-            rank_mask, sigma, sigma_cho, sigma_li)
+            rank_mask = allocate_rank_masks(sigma, rank_budget, rank)
+            sigma, sigma_cho, sigma_li = _apply_rank_masks(
+                rank_mask, sigma, sigma_cho, sigma_li)
 
-    # leaf factors: leaf-granularity stages under shard_map, parent
-    # stacks repeated per leaf (the streaming engine's layout)
-    leaves = x_sorted.reshape(n_leaves, n0, d)
-    adiag = _sharded_gram_fn(mesh, axis, kernel, config, False)(leaves)
-    u = _sharded_cross_fn(mesh, axis, kernel, config)(
-        leaves, jnp.repeat(landmarks[-1], 2, axis=0),
-        jnp.repeat(sigma_li[-1], 2, axis=0))
+        # leaf factors at leaf granularity, parent stacks repeated per leaf
+        # (the streaming engine's layout), on the row-sharded leaves
+        adiag, u = _leaf_stage_factors_jit(
+            x_sorted.reshape(n_leaves, n0, d),
+            jnp.repeat(landmarks[-1], 2, axis=0),
+            jnp.repeat(sigma_li[-1], 2, axis=0), kernel, config)
 
-    w = _dist_transfer_ops(landmarks, sigma_li, kernel, config, mesh, axis)
-    if rank_mask is not None:
-        u = u * jnp.repeat(rank_mask[-1], 2, axis=0)[:, None, :]
-        w = _mask_transfer_ops(w, rank_mask)
-    f = HCKFactors(x_sorted, tree, landmarks, tuple(sigma), tuple(sigma_cho),
-                   w, u, adiag, rank_mask)
-    return shard_by_subtree(f, mesh, axis=axis)
+        w = _transfer_ops_jit(landmarks, sigma_li, kernel, config)
+        if rank_mask is not None:
+            u = u * jnp.repeat(rank_mask[-1], 2, axis=0)[:, None, :]
+            w = _mask_transfer_ops(w, rank_mask)
+        f = HCKFactors(x_sorted, tree, landmarks, tuple(sigma),
+                       tuple(sigma_cho), w, u, adiag, rank_mask)
+        return shard_by_subtree(f, mesh, axis=axis)
 
 
 def dist_build_hck_streaming(source, *, levels: int, rank: int, key: Array,
@@ -587,9 +456,9 @@ def dist_build_hck_streaming(source, *, levels: int, rank: int, key: Array,
     builds at round-off.  The partition streams through
     :func:`repro.data.pipeline.stream_partition` with its projection
     chunks committed row-sharded (``mesh=``), landmark rows gather on
-    the host, and leaf batches whose size divides P run the shard_map
-    leaf stages — ragged tails fall back to the local launch (stage rows
-    are independent, so the values are identical either way).
+    the host, and leaf batches whose size divides P are row-sharded over
+    the mesh before their leaf stages — ragged tails stay on one device
+    (stage rows are independent, so the values are identical either way).
     """
     from repro.data.pipeline import stream_partition
     from repro.landmarks.policy import UniformPolicy, get_policy
@@ -618,53 +487,46 @@ def dist_build_hck_streaming(source, *, levels: int, rank: int, key: Array,
     if rank > n0:
         raise ValueError(f"rank {rank} exceeds leaf size {n0} (paper §4.4)")
 
-    kpart, key = jax.random.split(key)
-    perm_np, tree = stream_partition(source, levels, kpart, method=method,
-                                     chunk_rows=chunk_rows, mesh=mesh,
-                                     mesh_axis=axis)
+    with jax.set_mesh(mesh):
+        kpart, key = jax.random.split(key)
+        perm_np, tree = stream_partition(source, levels, kpart, method=method,
+                                         chunk_rows=chunk_rows, mesh=mesh,
+                                         mesh_axis=axis)
 
-    landmarks = []
-    for lvl in range(levels):
-        key, sub = jax.random.split(key)
-        bsz, m = 1 << lvl, n >> lvl
-        idx = np.asarray(landmark_indices(sub, bsz, m, rank))
-        rows = perm_np[(np.arange(bsz)[:, None] * m + idx).reshape(-1)]
-        landmarks.append(jnp.asarray(source.take(rows)).reshape(bsz, rank, d))
-    landmarks = tuple(landmarks)
+        landmarks = []
+        for lvl in range(levels):
+            key, sub = jax.random.split(key)
+            bsz, m = 1 << lvl, n >> lvl
+            idx = np.asarray(landmark_indices(sub, bsz, m, rank))
+            rows = perm_np[(np.arange(bsz)[:, None] * m + idx).reshape(-1)]
+            landmarks.append(jnp.asarray(source.take(rows)).reshape(bsz, rank, d))
+        landmarks = tuple(landmarks)
 
-    sigma, sigma_cho, sigma_li = _dist_middle_factors(
-        landmarks, kernel, config, mesh, axis)
+        sigma, sigma_cho, sigma_li = _middle_factors(landmarks, kernel, config)
 
-    lm_parent = jnp.repeat(landmarks[-1], 2, axis=0)
-    linv_parent = jnp.repeat(sigma_li[-1], 2, axis=0)
-    gram_fn = _sharded_gram_fn(mesh, axis, kernel, config, False)
-    cross_fn = _sharded_cross_fn(mesh, axis, kernel, config)
-    row_sh = NamedSharding(mesh, P(axis))
-    adiag_parts, u_parts, x_parts = [], [], []
-    for start in range(0, n_leaves, leaf_batch):
-        stop = min(start + leaf_batch, n_leaves)
-        rows = perm_np[start * n0:stop * n0]
-        blk = jnp.asarray(source.take(rows)).reshape(stop - start, n0, d)
-        x_parts.append(blk.reshape(-1, d))
-        if (stop - start) % p == 0:
-            blk = jax.device_put(blk, row_sh)
-            a = gram_fn(blk)
-            ub = cross_fn(blk, lm_parent[start:stop],
-                          linv_parent[start:stop])
-        else:
+        lm_parent = jnp.repeat(landmarks[-1], 2, axis=0)
+        linv_parent = jnp.repeat(sigma_li[-1], 2, axis=0)
+        row_sh = NamedSharding(mesh, P(axis))
+        adiag_parts, u_parts, x_parts = [], [], []
+        for start in range(0, n_leaves, leaf_batch):
+            stop = min(start + leaf_batch, n_leaves)
+            rows = perm_np[start * n0:stop * n0]
+            blk = jnp.asarray(source.take(rows)).reshape(stop - start, n0, d)
+            x_parts.append(blk.reshape(-1, d))
+            if (stop - start) % p == 0:
+                blk = jax.device_put(blk, row_sh)
             a, ub = leaf_stage_factors(blk, lm_parent[start:stop],
-                                       linv_parent[start:stop], kernel,
-                                       config)
-        adiag_parts.append(a)
-        u_parts.append(ub)
-    adiag = jnp.concatenate(adiag_parts, axis=0)
-    u = jnp.concatenate(u_parts, axis=0)
-    x_sorted = jnp.concatenate(x_parts, axis=0)
+                                       linv_parent[start:stop], kernel, config)
+            adiag_parts.append(a)
+            u_parts.append(ub)
+        adiag = jnp.concatenate(adiag_parts, axis=0)
+        u = jnp.concatenate(u_parts, axis=0)
+        x_sorted = jnp.concatenate(x_parts, axis=0)
 
-    w = _dist_transfer_ops(landmarks, sigma_li, kernel, config, mesh, axis)
-    f = HCKFactors(x_sorted, tree, landmarks, tuple(sigma), tuple(sigma_cho),
-                   w, u, adiag)
-    return shard_by_subtree(f, mesh, axis=axis)
+        w = _transfer_ops(landmarks, sigma_li, kernel, config)
+        f = HCKFactors(x_sorted, tree, landmarks, tuple(sigma), tuple(sigma_cho),
+                       w, u, adiag)
+        return shard_by_subtree(f, mesh, axis=axis)
 
 
 def dist_sweep_factors(plan, kernel: BaseKernel, mesh: Mesh,
@@ -685,7 +547,8 @@ def dist_sweep_factors(plan, kernel: BaseKernel, mesh: Mesh,
     """
     from repro.core.hck import sweep_factors
 
-    plan = shard_by_subtree(plan, mesh, axis=axis)
-    return shard_by_subtree(
-        sweep_factors(plan, kernel, config, rank_budget=rank_budget), mesh,
-        axis=axis)
+    with jax.set_mesh(mesh):
+        plan = shard_by_subtree(plan, mesh, axis=axis)
+        return shard_by_subtree(
+            sweep_factors(plan, kernel, config, rank_budget=rank_budget), mesh,
+            axis=axis)

@@ -10,7 +10,7 @@ Nothing below assumes those numbers; MeshConfig is config.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh, NamedSharding
+from jax.sharding import AxisType, Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, MeshConfig, ShapeConfig
@@ -20,7 +20,7 @@ from repro.models import transformer as tf
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def kernel_mesh(n_devices: int | None = None, axis: str = "dev") -> Mesh:
@@ -43,11 +43,15 @@ def kernel_mesh(n_devices: int | None = None, axis: str = "dev") -> Mesh:
             "are visible (set XLA_FLAGS="
             "--xla_force_host_platform_device_count=N for a virtual mesh)")
     device_level(n)          # validates the power-of-two constraint
-    return jax.make_mesh((n,), (axis,), devices=jax.devices()[:n])
+    # Auto axes: the pipeline places its arrays with NamedSharding and lets
+    # GSPMD partition the rest (jax.make_mesh now defaults to Explicit)
+    return jax.make_mesh((n,), (axis,), devices=jax.devices()[:n],
+                         axis_types=(AxisType.Auto,))
 
 
 def make_mesh(cfg: MeshConfig) -> Mesh:
-    return jax.make_mesh(cfg.shape, cfg.axis_names)
+    return jax.make_mesh(cfg.shape, cfg.axis_names,
+                         axis_types=(AxisType.Auto,) * len(cfg.axis_names))
 
 
 def mesh_config_for(mesh: Mesh) -> MeshConfig:

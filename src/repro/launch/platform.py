@@ -1,18 +1,31 @@
-"""Process-level platform setup: XLA flags BEFORE the first jax import.
+"""Process-level platform setup: environment BEFORE the first jax import.
 
-jax locks its platform list and XLA flag set at first backend init, so
-every entry point (launch/train.py, benchmarks/*) must route through
-:func:`setup_platform` before importing jax.  The module itself imports
-no jax for the same reason.
+jax reads its platform list, XLA flags and config defaults from the
+environment at first import / backend init, so every entry point
+(``chip_smoke.py``, launch/train.py, launch/serve.py, benchmarks/*) must
+route through :func:`setup_platform` before importing jax.  The module
+itself imports no jax for the same reason.
 
 What it sets:
 
   * ``JAX_PLATFORMS`` — from the ``platform`` argument or the
-    ``REPRO_PLATFORM`` env var (cpu / gpu / tpu).  Unset means jax's own
+    ``REPRO_PLATFORM`` env var (cpu / tpu).  Unset means jax's own
     auto-detection order.
-  * the GPU XLA flag set (triton gemm/softmax fusion, async collectives,
-    latency-hiding scheduler) — applied when targeting gpu, either
-    explicitly or because an NVIDIA driver is visible.
+  * ``JAX_COMPILATION_CACHE_DIR`` — the persistent compile cache.  A value
+    already in the environment is kept as it is; otherwise the cache goes
+    to :data:`DEFAULT_CACHE_DIR`, one fixed directory inside the checkout
+    (the path is part of the cache key, so it must not move between runs).
+  * ``JAX_DEFAULT_MATMUL_PRECISION=highest`` unless already set: the TPU's
+    default f32 matmul is one bfloat16 pass.  JAX reads this setting when
+    it traces an op, so it reaches every f32 matmul of the program: XLA
+    einsums, ``jnp.linalg`` internals and the Pallas kernels' own dots
+    (Mosaic then contracts in fp32).  Per-call ``precision=`` arguments
+    would need one at each of about a hundred call sites and still miss
+    the library internals.  On a TPU v5e at the Table-1 shape of
+    ``chip_smoke.py``, ``hmatrix.solve`` returns NaN without it, and it
+    makes the solve 3-4% slower (``PERF.md``).  CPU matmuls are f32
+    either way.  A program that imports ``repro`` without calling this
+    function sets the variable itself (README, Install).
   * ``--xla_force_host_platform_device_count`` — from ``host_devices`` or
     ``REPRO_HOST_DEVICES``, for virtual-mesh CPU runs.
 
@@ -23,23 +36,12 @@ returns a record of what was applied for logging.
 from __future__ import annotations
 
 import os
-import shutil
+import pathlib
 import sys
 import warnings
 
-#: XLA flags that pay off on CUDA GPUs (fusion + comm/compute overlap).
-GPU_XLA_FLAGS = (
-    "--xla_gpu_enable_triton_softmax_fusion=true",
-    "--xla_gpu_triton_gemm_any=True",
-    "--xla_gpu_enable_async_collectives=true",
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-    "--xla_gpu_enable_highest_priority_async_stream=true",
-)
-
-
-def gpu_visible() -> bool:
-    """Best-effort NVIDIA-driver detection without importing jax."""
-    return shutil.which("nvidia-smi") is not None
+#: persistent compile cache used when ``JAX_COMPILATION_CACHE_DIR`` is unset
+DEFAULT_CACHE_DIR = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 def _merge_xla_flags(new_flags) -> list:
@@ -54,7 +56,7 @@ def _merge_xla_flags(new_flags) -> list:
 
 def setup_platform(platform: str | None = None,
                    host_devices: int | None = None) -> dict:
-    """Configure the jax platform/XLA flags for this process.
+    """Configure the jax platform, compile cache and XLA flags.
 
     Call before the first ``import jax``; warns (but still applies the
     env, for any later-spawned subprocess) when jax is already imported.
@@ -71,17 +73,14 @@ def setup_platform(platform: str | None = None,
         hd = os.environ.get("REPRO_HOST_DEVICES")
         host_devices = int(hd) if hd else None
 
+    os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(DEFAULT_CACHE_DIR))
+    os.environ.setdefault("JAX_DEFAULT_MATMUL_PRECISION", "highest")
     applied = {"platform": platform, "host_devices": host_devices,
+               "compilation_cache_dir": os.environ["JAX_COMPILATION_CACHE_DIR"],
                "flags": []}
     if platform:
         os.environ.setdefault("JAX_PLATFORMS", platform)
         applied["platform"] = os.environ["JAX_PLATFORMS"]
-    targets_gpu = (platform == "gpu"
-                   or (platform is None
-                       and os.environ.get("JAX_PLATFORMS") in (None, "")
-                       and gpu_visible()))
-    if targets_gpu:
-        applied["flags"] += _merge_xla_flags(GPU_XLA_FLAGS)
     if host_devices:
         applied["flags"] += _merge_xla_flags(
             (f"--xla_force_host_platform_device_count={host_devices}",))

@@ -22,8 +22,12 @@ from __future__ import annotations
 import argparse
 import time
 
-import jax
-import jax.numpy as jnp
+from repro.launch.platform import setup_platform
+
+setup_platform()    # JAX_PLATFORMS/compile cache must land before jax loads
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
 
 
 def run_lm(args):

@@ -65,7 +65,7 @@ import argparse
 
 from repro.launch.platform import setup_platform
 
-setup_platform()    # JAX_PLATFORMS/XLA flags must land before jax loads
+setup_platform()    # JAX_PLATFORMS/compile cache must land before jax loads
 
 import jax  # noqa: E402
 

@@ -82,10 +82,9 @@ def axis_dot(axis: str) -> Callable[[Array, Array], Array]:
     Outside shard_map (plain jit on sharded arrays) no hook is needed:
     GSPMD already composes the partial sums.
 
-    A ``shard_map`` body that calls :func:`pcg` must pass
-    ``check_rep=False`` to ``shard_map`` — jax has no replication rule
-    for the solver's ``lax.while_loop`` (the psum'd scalars are in fact
-    replicated; the flag only skips the static check).
+    ``jax.shard_map``'s default ``check_vma=True`` accepts a body that
+    calls :func:`pcg`: the psum'd scalars type as mesh-invariant, so the
+    solver's ``lax.while_loop`` carries check as they are.
     """
     def dot(u: Array, v: Array) -> Array:
         return jax.lax.psum(jnp.sum(u * v, axis=0), axis)

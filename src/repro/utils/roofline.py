@@ -163,13 +163,13 @@ def model_flops(param_count: int, tokens: int, kind: str) -> float:
 # ---------------------------------------------------------------------------
 
 def default_device_kind() -> str:
-    """Coarse device kind of the default jax backend: cpu / gpu / tpu."""
+    """Coarse device kind of the default jax backend: cpu / gpu / tpu.
+
+    A backend that fails to initialize raises rather than reading as cpu.
+    """
     import jax
 
-    try:
-        backend = jax.default_backend()
-    except Exception:   # noqa: BLE001 — uninitialized backends -> cpu
-        return "cpu"
+    backend = jax.default_backend()
     if backend in ("gpu", "cuda", "rocm"):
         return "gpu"
     return backend if backend in HW_MODELS else "cpu"

@@ -100,6 +100,38 @@ def mesh8():
     return kernel_mesh(8)
 
 
+def test_dist_build_runs_build_hck_partition_program(monkeypatch):
+    """The mesh build partitions with build_hck's own compiled program on
+    the same points and key: the tree is a discrete function of rounded
+    projections, so a re-implementation that agrees on the CPU may still
+    move points across a node's median on a TPU."""
+    from repro.core import hck, partition
+    from repro.core.kernels_fn import BaseKernel
+    from repro.launch.dist_hck import dist_build_hck
+    from repro.launch.mesh import kernel_mesh
+
+    calls = []
+    original = partition.build_partition
+
+    def recording(x, levels, key, method="rp"):
+        calls.append((x, levels, np.asarray(key), method))
+        return original(x, levels, key, method=method)
+
+    monkeypatch.setattr(hck, "build_partition", recording)
+    monkeypatch.setattr(partition, "build_partition", recording)
+    ker = BaseKernel("gaussian", sigma=2.0)
+    x = jax.random.normal(jax.random.PRNGKey(0), (256, 3), dtype=jnp.float32)
+    key = jax.random.PRNGKey(1)
+    f_ref = hck.build_hck(x, levels=3, rank=8, key=key, kernel=ker)
+    f_dist = dist_build_hck(x, levels=3, rank=8, key=key, kernel=ker,
+                            mesh=kernel_mesh(1))
+    assert len(calls) == 2
+    (x_a, lv_a, k_a, m_a), (x_b, lv_b, k_b, m_b) = calls
+    assert x_a is x and x_b is x
+    assert (lv_a, m_a) == (lv_b, m_b) and (k_a == k_b).all()
+    assert (np.asarray(f_dist.tree.perm) == np.asarray(f_ref.tree.perm)).all()
+
+
 @needs_mesh
 def test_dist_build_matches_single_host(f64, mesh8):
     """dist_build_hck == build_hck at 1e-12 in f64 (same key → same

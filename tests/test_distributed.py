@@ -263,10 +263,9 @@ needs_mesh = pytest.mark.skipif(
 @needs_mesh
 def test_axis_dot_cg_mesh_invariance(f64):
     """pcg inside an explicit shard_map body with the psum inner product
-    (axis_dot) == the single-host solve: SAME iteration count, same x.
-    check_rep=False is required (no replication rule for while_loop)."""
+    (axis_dot) == the single-host solve: SAME iteration count, same x,
+    under shard_map's default varying-axes check."""
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import kernel_mesh
@@ -286,9 +285,9 @@ def test_axis_dot_cg_mesh_invariance(f64):
                 maxiter=200, dot=axis_dot("dev"))
         return r.x, r.iterations
 
-    x_mesh, it_mesh = jax.jit(shard_map(
+    x_mesh, it_mesh = jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=(P("dev"), P("dev")),
-        out_specs=(P("dev"), P()), check_rep=False))(diag, b)
+        out_specs=(P("dev"), P())))(diag, b)
     assert int(it_mesh) == int(r_host.iterations)
     assert bool(r_host.converged)
     assert float(jnp.max(jnp.abs(x_mesh - r_host.x))) < 1e-10
@@ -301,7 +300,6 @@ def test_slq_logdet_shard_map_contract(f64):
     logdet of a diagonal operator with few distinct eigenvalues (the
     quadrature is exact once iters exceeds the spectrum size)."""
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.launch.mesh import kernel_mesh
@@ -321,8 +319,8 @@ def test_slq_logdet_shard_map_contract(f64):
             key=key, dtype=jnp.float64,
             all_reduce=lambda s: jax.lax.psum(s, "dev"), n_total=n)
 
-    ld = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("dev"),),
-                           out_specs=P(), check_rep=False))(diag)
+    ld = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(P("dev"),),
+                               out_specs=P()))(diag)
     assert abs(float(ld) - exact) < 1e-8 * abs(exact)
 
 

@@ -191,9 +191,13 @@ def test_resolve_backend_covers_oos_stages():
 
 
 def test_tile_config_oos_query_blocks():
-    t = tile_config("oos_local", n0=256, r=0, k=1, d=8)
+    t = tile_config("oos_local", n0=64, r=0, k=1, d=8)
     assert t.block_n0 == 128 and t.fits            # default query block
-    big = tile_config("oos_local", n0=2048, r=0, k=1, d=8)
+    # VMEM tiles are counted as laid out: a (n0, d=8) point block pads its
+    # lanes to 128, so the Table-1 leaf (128, 90) halves the default block
+    table1 = tile_config("oos_local", n0=128, r=0, k=1, d=90)
+    assert table1.block_n0 == 64 and table1.fits
+    big = tile_config("oos_local", n0=512, r=0, k=1, d=8)
     assert big.fits and big.block_n0 < 128         # shrinks to the budget
     huge = tile_config("oos_local", n0=65536, r=0, k=1, d=64)
     assert not huge.fits and huge.block_n0 == 8    # floor block, reported
