@@ -47,6 +47,7 @@ from repro.core.partition import PartitionTree, build_partition
 from repro.kernels.registry import (DEFAULT_CONFIG, SolveConfig, get_impl,
                                     precision_policy, resolve_backend,
                                     tile_config)
+from repro.runtime.trace import span
 
 Array = jax.Array
 
@@ -423,11 +424,13 @@ def build_hck(
                          "(a 0-level build has no low-rank factors)")
 
     kpart, key = jax.random.split(key)
-    x_sorted, tree = build_partition(x, levels, kpart, method=method)
-    return _build_on_tree(x_sorted, tree, key, levels=levels, rank=rank,
-                          kernel=kernel, shared_landmarks=shared_landmarks,
-                          config=config, policy=policy,
-                          rank_budget=rank_budget)
+    with span("partition"):
+        x_sorted, tree = build_partition(x, levels, kpart, method=method)
+    with span("build"):
+        return _build_on_tree(x_sorted, tree, key, levels=levels, rank=rank,
+                              kernel=kernel, shared_landmarks=shared_landmarks,
+                              config=config, policy=policy,
+                              rank_budget=rank_budget)
 
 
 @functools.partial(

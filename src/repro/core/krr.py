@@ -20,6 +20,7 @@ from repro.core.kernels_fn import BaseKernel
 from repro.core.partition import auto_levels, auto_levels_ceil, pad_points
 from repro.kernels.registry import SolveConfig
 from repro.runtime import health
+from repro.runtime.trace import span
 
 Array = jax.Array
 
@@ -155,39 +156,44 @@ def fit(
     rank_budget: global rank budget for budgeted adaptive per-node rank
                (see :func:`repro.core.hck.build_hck`).
     """
-    key = key if key is not None else jax.random.PRNGKey(0)
-    n = x.shape[0]
-    leaf_size = leaf_size if leaf_size is not None else rank
-    if levels is None:
-        levels = max(1, auto_levels_ceil(n, leaf_size))
-    kpad, kbuild = jax.random.split(key)
-    x, y, mask = pad_points(x, y, leaf_size, levels, kpad)
+    with span("fit"):
+        with span("fit.pad"):
+            key = key if key is not None else jax.random.PRNGKey(0)
+            n = x.shape[0]
+            leaf_size = leaf_size if leaf_size is not None else rank
+            if levels is None:
+                levels = max(1, auto_levels_ceil(n, leaf_size))
+            kpad, kbuild = jax.random.split(key)
+            x, y, mask = pad_points(x, y, leaf_size, levels, kpad)
 
-    targets, classes, squeeze = _encode_targets(y, classification)
-    del mask  # padded rows carry duplicated targets (see pad_points)
+            targets, classes, squeeze = _encode_targets(y, classification)
+            del mask  # padded rows carry duplicated targets (see pad_points)
 
-    factors = build_hck(
-        x, levels=levels, rank=rank, key=kbuild, kernel=kernel,
-        method=method, shared_landmarks=shared_landmarks, config=solve_config,
-        policy=landmarks, rank_budget=rank_budget,
-    )
-    health.probe_factors(factors, solve_config, op="build")
-    y_sorted = targets[factors.tree.perm]
-    # solve via the leaf-aware inverse and CACHE it on the model: the pair
-    # is what fit_incremental's bordered extension reuses, so the FIRST
-    # online update is as cheap as the rest (inv equals hmatrix.invert's,
-    # so alpha is the same solve as before)
-    inv, lo = hmatrix.invert_with_leaf(factors, lam, solve_config)
-    health.probe_leaf_factor(lo, solve_config)
-    alpha = hmatrix.solve_with_inverse(factors, inv, y_sorted, ridge=lam,
-                                       config=solve_config)
-    health.check_finite("solve", alpha, config=solve_config,
-                        detail="dual coefficients (fit)")
-    plan = oos.prepare(factors, alpha, solve_config)
-    return HCKRegressor(kernel, factors, plan, alpha, classes,
-                        squeeze=squeeze, solve_config=solve_config,
-                        lam=lam, base_leaf_size=factors.leaf_size,
-                        inverse=inv, leaf_lo=lo)
+        factors = build_hck(
+            x, levels=levels, rank=rank, key=kbuild, kernel=kernel,
+            method=method, shared_landmarks=shared_landmarks,
+            config=solve_config, policy=landmarks, rank_budget=rank_budget,
+        )
+        health.probe_factors(factors, solve_config, op="build")
+        # solve via the leaf-aware inverse and CACHE it on the model: the
+        # pair is what fit_incremental's bordered extension reuses, so the
+        # FIRST online update is as cheap as the rest (inv equals
+        # hmatrix.invert's, so alpha is the same solve as before)
+        with span("invert"):
+            inv, lo = hmatrix.invert_with_leaf(factors, lam, solve_config)
+        health.probe_leaf_factor(lo, solve_config)
+        with span("solve"):
+            y_sorted = targets[factors.tree.perm]
+            alpha = hmatrix.solve_with_inverse(factors, inv, y_sorted,
+                                               ridge=lam, config=solve_config)
+        health.check_finite("solve", alpha, config=solve_config,
+                            detail="dual coefficients (fit)")
+        with span("prepare"):
+            plan = oos.prepare(factors, alpha, solve_config)
+        return HCKRegressor(kernel, factors, plan, alpha, classes,
+                            squeeze=squeeze, solve_config=solve_config,
+                            lam=lam, base_leaf_size=factors.leaf_size,
+                            inverse=inv, leaf_lo=lo)
 
 
 def fit_streaming(

@@ -23,8 +23,11 @@ Probes are gated by ``SolveConfig.checks`` with the ``REPRO_STRICT_FINITE``
 env var as the default policy, and every probe no-ops on traced values —
 they run at stage boundaries OUTSIDE jit, so compiled programs are
 bitwise identical with checks on or off and the checks-off hot path pays
-one predicate per boundary (gated ≤ 3% end to end in
-``benchmarks/bench_oos.py`` / ``bench_update.py``).
+one predicate per boundary.  The serving loop's probe is forced, not
+gated: on one TPU v5e it takes 1.19–1.22 ms per served batch (three
+small programs and a synchronous read) against a median request latency
+of 12.3 ms at 16,000 requests/s (PERF.md, ``msd.serve``).  A probe that
+runs records a ``hck.probe.<stage>`` span.
 """
 from __future__ import annotations
 
@@ -34,6 +37,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.runtime.trace import span
 
 Array = jax.Array
 
@@ -149,12 +154,13 @@ def check_finite(stage: str, x: Array, *, config=None, force: bool = False,
     """
     if not _gate(config, force, x):
         return False
-    bad = ~jnp.isfinite(x)
-    if bool(jnp.any(bad)):
-        raise NumericalFailure(
-            stage, statistic=statistic, value=int(jnp.sum(bad)),
-            leaf=_first_bad_leaf(bad, leaf_axis), dtype=x.dtype,
-            backend=_backend_of(config), detail=detail)
+    with span("probe." + stage):
+        bad = ~jnp.isfinite(x)
+        if bool(jnp.any(bad)):
+            raise NumericalFailure(
+                stage, statistic=statistic, value=int(jnp.sum(bad)),
+                leaf=_first_bad_leaf(bad, leaf_axis), dtype=x.dtype,
+                backend=_backend_of(config), detail=detail)
     return True
 
 
@@ -181,38 +187,41 @@ def probe_factors(factors, config=None, *, force: bool = False,
     """
     if not _gate(config, force, factors.adiag, factors.u):
         return False
-    # happy path: one fused compiled predicate over the whole factor
-    # pytree and a SINGLE host sync — per-factor probes cost ~1 ms each
-    # in syncs and dispatch, which is the difference between probes
-    # cheap enough to leave on in production and probes that blow the
-    # bench_update recovery-overhead budget.  The per-factor attribution
-    # below only runs once something is already known to be bad.
-    leaves = [factors.adiag, factors.u, *factors.sigma, *factors.sigma_cho,
-              *factors.w]
-    if bool(_all_finite_pd(leaves, list(factors.sigma_cho))):
+    with span("probe." + op):
+        # happy path: one fused compiled predicate over the whole factor
+        # pytree and a SINGLE host sync — per-factor probes cost ~1 ms each
+        # in syncs and dispatch, which is the difference between probes
+        # cheap enough to leave on in production and probes that blow the
+        # bench_update recovery-overhead budget.  The per-factor attribution
+        # below only runs once something is already known to be bad.
+        leaves = [factors.adiag, factors.u, *factors.sigma, *factors.sigma_cho,
+                  *factors.w]
+        if bool(_all_finite_pd(leaves, list(factors.sigma_cho))):
+            return True
+        check_finite("build_gram", factors.adiag, config=config, force=True,
+                     leaf_axis=0, detail=f"op={op} factor=adiag")
+        for lvl, (sig, cho) in enumerate(zip(factors.sigma,
+                                             factors.sigma_cho)):
+            check_finite("build_gram", sig, config=config, force=True,
+                         leaf_axis=0,
+                         detail=f"op={op} factor=sigma level={lvl}")
+            check_finite("build_gram", cho, config=config, force=True,
+                         leaf_axis=0,
+                         detail=f"op={op} factor=sigma_cho level={lvl}")
+            diag = jnp.diagonal(cho, axis1=-2, axis2=-1)
+            if bool(jnp.any(diag <= 0)):
+                raise NumericalFailure(
+                    "build_gram", statistic="min_cholesky_diag",
+                    value=float(jnp.min(diag)),
+                    node=_first_bad_leaf(diag <= 0, 0), dtype=cho.dtype,
+                    backend=_backend_of(config),
+                    detail=f"op={op} Sigma Cholesky not PD at level {lvl}")
+        check_finite("build_cross", factors.u, config=config, force=True,
+                     leaf_axis=0, detail=f"op={op} factor=u")
+        for lvl, w in enumerate(factors.w):
+            check_finite("build_cross", w, config=config, force=True,
+                         leaf_axis=0, detail=f"op={op} factor=w level={lvl}")
         return True
-    check_finite("build_gram", factors.adiag, config=config, force=True,
-                 leaf_axis=0, detail=f"op={op} factor=adiag")
-    for lvl, (sig, cho) in enumerate(zip(factors.sigma, factors.sigma_cho)):
-        check_finite("build_gram", sig, config=config, force=True,
-                     leaf_axis=0, detail=f"op={op} factor=sigma level={lvl}")
-        check_finite("build_gram", cho, config=config, force=True,
-                     leaf_axis=0,
-                     detail=f"op={op} factor=sigma_cho level={lvl}")
-        diag = jnp.diagonal(cho, axis1=-2, axis2=-1)
-        if bool(jnp.any(diag <= 0)):
-            raise NumericalFailure(
-                "build_gram", statistic="min_cholesky_diag",
-                value=float(jnp.min(diag)),
-                node=_first_bad_leaf(diag <= 0, 0), dtype=cho.dtype,
-                backend=_backend_of(config),
-                detail=f"op={op} Sigma Cholesky not PD at level {lvl}")
-    check_finite("build_cross", factors.u, config=config, force=True,
-                 leaf_axis=0, detail=f"op={op} factor=u")
-    for lvl, w in enumerate(factors.w):
-        check_finite("build_cross", w, config=config, force=True,
-                     leaf_axis=0, detail=f"op={op} factor=w level={lvl}")
-    return True
 
 
 def probe_leaf_factor(lo: Array, config=None, *, force: bool = False,
@@ -227,17 +236,18 @@ def probe_leaf_factor(lo: Array, config=None, *, force: bool = False,
     """
     if not _gate(config, force, lo):
         return False
-    diag = jnp.diagonal(lo, axis1=-2, axis2=-1)        # (P, n0)
-    bad = ~jnp.isfinite(diag) | (diag <= 0)
-    if bool(jnp.any(bad)):
-        raise NumericalFailure(
-            stage, statistic="min_schur_cholesky_diag",
-            value=float(jnp.min(jnp.where(jnp.isfinite(diag), diag,
-                                          -jnp.inf))),
-            leaf=_first_bad_leaf(bad, 0), dtype=lo.dtype,
-            backend=_backend_of(config),
-            detail="leaf Schur complement indefinite or non-finite "
-                   "(raise the ridge, promote precision, or refit)")
+    with span("probe." + stage):
+        diag = jnp.diagonal(lo, axis1=-2, axis2=-1)        # (P, n0)
+        bad = ~jnp.isfinite(diag) | (diag <= 0)
+        if bool(jnp.any(bad)):
+            raise NumericalFailure(
+                stage, statistic="min_schur_cholesky_diag",
+                value=float(jnp.min(jnp.where(jnp.isfinite(diag), diag,
+                                              -jnp.inf))),
+                leaf=_first_bad_leaf(bad, 0), dtype=lo.dtype,
+                backend=_backend_of(config),
+                detail="leaf Schur complement indefinite or non-finite "
+                       "(raise the ridge, promote precision, or refit)")
     return True
 
 

@@ -38,6 +38,7 @@ from repro.core.hck import HCKFactors
 from repro.core.kernels_fn import BaseKernel
 from repro.kernels.registry import SolveConfig
 from repro.runtime import health
+from repro.runtime.trace import span
 
 Array = jax.Array
 
@@ -146,21 +147,22 @@ class PredictEngine:
         not deep inside a stage launch; with health checks on
         (``SolveConfig.checks`` / ``REPRO_STRICT_FINITE``) non-finite
         predictions raise a structured ``NumericalFailure``."""
-        validate_queries(queries, self.factors.x_sorted)
-        q = queries.shape[0]
-        if q == 0:
-            k = self.plan.w_leaf.shape[-1]
-            return jnp.zeros((0, k), self.plan.w_leaf.dtype)
-        if q > self.max_bucket:
-            return jnp.concatenate(
-                [self.apply(queries[i:i + self.max_bucket])
-                 for i in range(0, q, self.max_bucket)], axis=0)
-        b = bucket_size(q, self.min_bucket, self.max_bucket)
-        padded = jnp.pad(queries, ((0, b - q), (0, 0)), mode="edge")
-        z = oos.apply_plan(self.factors, self.plan, padded, self.kernel,
-                           self.config)
-        z = z[:q]
-        health.probe_predictions(z, self.config)
+        with span("engine"):
+            validate_queries(queries, self.factors.x_sorted)
+            q = queries.shape[0]
+            if q == 0:
+                k = self.plan.w_leaf.shape[-1]
+                return jnp.zeros((0, k), self.plan.w_leaf.dtype)
+            if q > self.max_bucket:
+                return jnp.concatenate(
+                    [self.apply(queries[i:i + self.max_bucket])
+                     for i in range(0, q, self.max_bucket)], axis=0)
+            b = bucket_size(q, self.min_bucket, self.max_bucket)
+            padded = jnp.pad(queries, ((0, b - q), (0, 0)), mode="edge")
+            z = oos.apply_plan(self.factors, self.plan, padded, self.kernel,
+                               self.config)
+            z = z[:q]
+            health.probe_predictions(z, self.config)
         self._calls += 1
         self._queries += q
         self._padded += b - q

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs.base import ArchConfig
 from repro.models import transformer as tf
 from repro.models.model_zoo import make_decode_step, make_prefill_step
+from repro.runtime.trace import span
 
 Array = jax.Array
 
@@ -84,75 +85,78 @@ class KRRServeLoop:
         non-finite response or a missed deadline."""
         from repro.runtime import health
 
-        t0 = time.perf_counter()
-        try:
-            z = entry.engine(queries)
-            jax.block_until_ready(z)
-        except health.NumericalFailure:
-            raise
-        except ValueError:
-            raise    # malformed batch: a caller bug, not an engine fault
-        except Exception as e:
-            # an engine that throws (OOM, a dead device, a poisoned jit
-            # cache) enters the same retry/degraded ladder as one that
-            # returns garbage
-            raise health.NumericalFailure(
-                "serve", statistic="engine_error", value=type(e).__name__,
-                detail=f"version {entry.version}: {e}")
-        dt = time.perf_counter() - t0
-        # serving always validates its output: this is the last line of
-        # defense between a poisoned model and a client (the canary gate
-        # is the first), so it is NOT gated on SolveConfig.checks
-        health.probe_predictions(z, force=True, stage="serve")
-        if self.deadline_s is not None and dt > self.deadline_s:
-            self._deadline_misses += 1
-            raise health.NumericalFailure(
-                "serve", statistic="deadline_s", value=dt,
-                detail=f"budget {self.deadline_s:g}s, version "
-                       f"{entry.version}")
-        return z, dt
+        with span("serve.attempt"):
+            t0 = time.perf_counter()
+            try:
+                z = entry.engine(queries)
+                with span("serve.wait"):
+                    jax.block_until_ready(z)
+            except health.NumericalFailure:
+                raise
+            except ValueError:
+                raise    # malformed batch: a caller bug, not an engine fault
+            except Exception as e:
+                # an engine that throws (OOM, a dead device, a poisoned jit
+                # cache) enters the same retry/degraded ladder as one that
+                # returns garbage
+                raise health.NumericalFailure(
+                    "serve", statistic="engine_error", value=type(e).__name__,
+                    detail=f"version {entry.version}: {e}")
+            dt = time.perf_counter() - t0
+            # serving always validates its output: this is the last line of
+            # defense between a poisoned model and a client (the canary gate
+            # is the first), so it is NOT gated on SolveConfig.checks
+            health.probe_predictions(z, force=True, stage="serve")
+            if self.deadline_s is not None and dt > self.deadline_s:
+                self._deadline_misses += 1
+                raise health.NumericalFailure(
+                    "serve", statistic="deadline_s", value=dt,
+                    detail=f"budget {self.deadline_s:g}s, version "
+                           f"{entry.version}")
+            return z, dt
 
     def serve(self, queries: Array) -> ServedBatch:
         """Serve one micro-batch; record and return the stamped response."""
         from repro.runtime.health import NumericalFailure
 
-        failure: Exception | None = None
-        retries = 0
-        for attempt in range(self.max_retries + 1):
-            entry = self.registry.live      # fresh snapshot per attempt
-            if entry is None:
-                raise ValueError("registry has no live model")
-            try:
-                z, dt = self._attempt(entry, queries)
-            except NumericalFailure as e:
-                self._failures += 1
-                failure = e
-                retries = attempt
-                if attempt < self.max_retries and self.backoff_s > 0:
-                    time.sleep(self.backoff_s * 2.0 ** attempt)
-                continue
-            out = ServedBatch(z, entry.version, dt, retries=attempt,
-                              failure=str(failure) if failure else None)
-            self._retries += attempt
-            self._last_good = entry
+        with span("serve", batch=len(self.responses)):
+            failure: Exception | None = None
+            retries = 0
+            for attempt in range(self.max_retries + 1):
+                entry = self.registry.live      # fresh snapshot per attempt
+                if entry is None:
+                    raise ValueError("registry has no live model")
+                try:
+                    z, dt = self._attempt(entry, queries)
+                except NumericalFailure as e:
+                    self._failures += 1
+                    failure = e
+                    retries = attempt
+                    if attempt < self.max_retries and self.backoff_s > 0:
+                        time.sleep(self.backoff_s * 2.0 ** attempt)
+                    continue
+                out = ServedBatch(z, entry.version, dt, retries=attempt,
+                                  failure=str(failure) if failure else None)
+                self._retries += attempt
+                self._last_good = entry
+                self.responses.append(out)
+                return out
+
+            # degraded mode: the live version is unservable — fall back to
+            # the last version that answered cleanly, surfacing the failure
+            fallback = self._last_good
+            if fallback is None or fallback.version == entry.version:
+                raise failure
+            t0 = time.perf_counter()
+            z = fallback.engine(queries)
+            jax.block_until_ready(z)
+            out = ServedBatch(z, fallback.version, time.perf_counter() - t0,
+                              degraded=True, retries=retries,
+                              failure=str(failure))
+            self._retries += retries
+            self._degraded += 1
             self.responses.append(out)
             return out
-
-        # degraded mode: the live version is unservable — fall back to the
-        # last version that answered cleanly, surfacing the failure
-        fallback = self._last_good
-        if fallback is None or fallback.version == entry.version:
-            raise failure
-        t0 = time.perf_counter()
-        z = fallback.engine(queries)
-        jax.block_until_ready(z)
-        out = ServedBatch(z, fallback.version, time.perf_counter() - t0,
-                          degraded=True, retries=retries,
-                          failure=str(failure))
-        self._retries += retries
-        self._degraded += 1
-        self.responses.append(out)
-        return out
 
     def run(self, queries: Array, micro_batch: int) -> list:
         """Serve ``queries`` in ``micro_batch`` slices; return responses."""
