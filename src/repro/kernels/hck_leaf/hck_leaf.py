@@ -13,7 +13,9 @@ resident in VMEM — the leaf stage is ~2/3 of the 18nr matvec flops (paper
 §4.5), and for Algorithm 2's apply it folds the block-Cholesky triangular
 pair plus the self low-rank correction into one VMEM-resident pass.
 
-Grid: one program per leaf; for the matvec the n0 dimension is additionally
+Grid: one program per leaf (``hck_leaf_factor``: several leaves per
+program, so their serial factorization chains interleave); for the matvec
+the n0 dimension is additionally
 row-tiled by the registry's per-shape
 :func:`repro.kernels.registry.tile_config` when a leaf does not fit the
 VMEM budget (default n0<=512 fits whole).  ``hck_leaf_solve`` chains two
@@ -32,6 +34,11 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.build_stage.build_stage import (_bdot, _cholesky_in_vmem,
+                                                   _pad_tiles, _panel_width,
+                                                   _tiles)
 
 Array = jax.Array
 
@@ -157,19 +164,9 @@ def hck_leaf_solve(
 # Leaf Schur-complement factorization (Algorithm 2 inversion)
 # ---------------------------------------------------------------------------
 
-def _tri_inv_in_vmem(lo: Array, m: int, acc) -> Array:
-    """Inverse of a lower-triangular (m, m) tile via one-hot forward
-    substitution.
-
-    Row ``i`` of ``X = lo^{-1}`` solves ``lo[i, i] X[i, :] = e_i -
-    lo[i, :] X`` where the contraction only touches the already-computed
-    rows < i.  Like the Cholesky loop, every step is a one-hot masked
-    rank-1 update — no dynamic slicing and no vector-shaped dot (Mosaic
-    refuses a contraction with a 1-D operand): rows are picked out with
-    masked sublane reductions and every operand stays 2-D, so the same
-    body lowers under Mosaic and interpret mode.  O(m^3/2) flops over an
-    m-step loop.
-    """
+def _tri_inv_onehot(lo: Array, m: int, acc) -> Array:
+    """Inverse of lower-triangular (..., m, m) tiles by m one-hot steps of
+    forward substitution, each a masked rank-1 update of the whole tile."""
     rows = jax.lax.broadcasted_iota(jnp.int32, (m, 1), 0)
     cols = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
     eye = (rows == cols).astype(acc)
@@ -177,24 +174,88 @@ def _tri_inv_in_vmem(lo: Array, m: int, acc) -> Array:
     def body(i, x):
         ei_col = (rows == i).astype(acc)                   # one-hot (m, 1)
         ei_row = (cols == i).astype(acc)                   # one-hot (1, m)
-        lrow = jnp.sum(lo * ei_col, axis=0, keepdims=True)     # row i (1, m)
-        lcol = jnp.sum(lrow * eye, axis=1, keepdims=True)      # as (m, 1)
-        s = jnp.sum(lcol * x, axis=0, keepdims=True)       # uses rows < i
-        pivot = jnp.sum(lrow * ei_row, axis=1, keepdims=True)  # lo[i, i]
+        lrow = jnp.sum(lo * ei_col, axis=-2, keepdims=True)    # row i (1, m)
+        lcol = jnp.sum(lrow * eye, axis=-1, keepdims=True)     # as (m, 1)
+        s = jnp.sum(lcol * x, axis=-2, keepdims=True)      # uses rows < i
+        pivot = jnp.sum(lrow * ei_row, axis=-1, keepdims=True)  # lo[i, i]
         newrow = (ei_row - s) / pivot
         return x + ei_col * newrow
 
-    return jax.lax.fori_loop(0, m, body, jnp.zeros((m, m), acc))
+    return jax.lax.fori_loop(0, m, body, jnp.zeros(lo.shape, acc))
+
+
+def _tri_inv_panel(rp: Array, z: Array, r0, m: int) -> Array:
+    """Solve ``L_kk X_k = z`` for the (T, b, m) row panel ``X_k`` of the
+    inverse, where ``rp`` holds rows ``r0 .. r0+b`` of ``R = L^T`` (so
+    ``L_kk[i', i] = rp[i, r0 + i']``): b one-hot substitution steps on the
+    panel alone."""
+    b = rp.shape[-2]
+    rows = jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, (1, m), 1)
+    diag = cols == rows + r0                               # (b, m)
+
+    def step(i, z):
+        rrow = jnp.sum(jnp.where(rows == i, rp, 0.0), axis=-2, keepdims=True)
+        lcol = jnp.sum(jnp.where(diag, rrow, 0.0), axis=-1,
+                       keepdims=True)                      # L[r0 + ., r0 + i]
+        pivot = jnp.sum(jnp.where(rows == i, lcol, 0.0), axis=-2,
+                        keepdims=True)
+        x = jnp.sum(jnp.where(rows == i, z, 0.0), axis=-2,
+                    keepdims=True) / pivot                 # row i of X_k
+        return jnp.where(rows == i, x,
+                         z - jnp.where(rows > i, lcol, 0.0) * x)
+
+    return jax.lax.fori_loop(0, b, step, z, unroll=True)
+
+
+def _tri_inv_in_vmem(lo: Array, m: int, acc) -> Array:
+    """Inverse of lower-triangular (T, m, m) or (m, m) tiles, in VMEM.
+
+    Blocked by row panels, right-looking like the Cholesky: the right sides
+    start as ``I``; panel ``k`` of ``X = lo^{-1}`` solves ``L_kk X_k =
+    B_k`` by b one-hot substitution steps on the (b, m) panel alone
+    (:func:`_tri_inv_panel`), and the rows below take ``B -= L_:k X_k`` as
+    one MXU product at ``Precision.HIGHEST`` — the blocked recursion
+    ``ref.tril_inverse`` runs in XLA, ``X21 = -L22^{-1} L21 L11^{-1}``,
+    taken one block column at a time.  ``L``'s column panels are read as
+    row panels of ``lo^T``, transposed once at the start.  Panel width, the
+    ``fori_loop`` over panels, the whole-tile update and the small-tile
+    fallback to the unblocked loop (:func:`_tri_inv_onehot`) follow the
+    Cholesky (``build_stage._cholesky_in_vmem``).
+    """
+    b = _panel_width(m)
+    if b is None:
+        return _tri_inv_onehot(lo, m, acc)
+    if lo.ndim == 2:
+        return _tri_inv_in_vmem(lo[None], m, acc)[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (m, m), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (m, m), 1)).astype(acc)
+
+    def invert(r_ref, x_ref):
+        r_ref[...] = jnp.swapaxes(lo, 1, 2)
+        x_ref[...] = jnp.broadcast_to(eye, lo.shape)   # right sides, in place
+
+        def panel(k, carry):
+            r0 = pl.multiple_of(k * b, b)
+            rp = r_ref[:, pl.ds(r0, b), :]
+            xk = _tri_inv_panel(rp, x_ref[:, pl.ds(r0, b), :], r0, m)
+            x_ref[...] -= _bdot(jnp.swapaxes(rp, 1, 2), xk, acc)
+            x_ref[:, pl.ds(r0, b), :] = xk
+            return carry
+
+        jax.lax.fori_loop(0, m // b, panel, 0)
+        return x_ref[...]
+
+    return pl.run_scoped(invert, pltpu.VMEM(lo.shape, acc),
+                         pltpu.VMEM(lo.shape, acc))
 
 
 def _factor_body(dleaf_ref, lo_ref, linv_ref, *, acc):
-    from repro.kernels.build_stage.build_stage import _cholesky_in_vmem
-
-    d = dleaf_ref[0]                                       # (n0, n0) SPD
-    m = d.shape[0]
+    d = dleaf_ref[...]                                     # (T, n0, n0) SPD
+    m = d.shape[-1]
     lo = _cholesky_in_vmem(d, m, acc)
-    lo_ref[0] = lo
-    linv_ref[0] = _tri_inv_in_vmem(lo, m, acc)
+    lo_ref[...] = lo
+    linv_ref[...] = _tri_inv_in_vmem(lo, m, acc)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -205,27 +266,30 @@ def hck_leaf_factor(
 
     (P, n0, n0) SPD leaf Schur complements -> (lo, linv), both (P, n0, n0)
     lower triangular with ``linv = lo^{-1}`` (so ``D^{-1} = linv^T linv``).
-    One program per leaf; the (n0, n0) tile never round-trips to HBM
-    between factorization and inversion.  Grid-batched over all leaves —
-    ``invert_multi`` stacks a whole (ridge-grid x leaves) batch into one
-    launch.
+    Each program factors as many leaves as
+    :func:`repro.kernels.registry.tile_config` fits in the VMEM budget, so
+    their serial panel chains interleave; a batch that is not a multiple is
+    padded with copies of its first tile.  Both halves are blocked by row
+    panels whose off-diagonal work runs on the MXU (``_cholesky_in_vmem``,
+    ``_tri_inv_in_vmem``); tiles of at most one panel take the unblocked
+    one-hot loops.  The tile never round-trips to HBM between factorization
+    and inversion.  Grid-batched over all leaves — ``invert_multi`` stacks
+    a whole (ridge-grid x leaves) batch into one launch.
     """
     p, n0, _ = dleaf.shape
     acc = _acc_dtype(dleaf)
-    return pl.pallas_call(
+    tiles = _tiles("leaf_factor", n0, 0, acc)
+    a = _pad_tiles(dleaf, tiles)
+    spec = pl.BlockSpec((tiles, n0, n0), lambda i: (i, 0, 0))
+    lo, linv = pl.pallas_call(
         functools.partial(_factor_body, acc=acc),
-        grid=(p,),
-        in_specs=[pl.BlockSpec((1, n0, n0), lambda i: (i, 0, 0))],
-        out_specs=[
-            pl.BlockSpec((1, n0, n0), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, n0, n0), lambda i: (i, 0, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((p, n0, n0), acc),
-            jax.ShapeDtypeStruct((p, n0, n0), acc),
-        ],
+        grid=(a.shape[0] // tiles,),
+        in_specs=[spec],
+        out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct(a.shape, acc)] * 2,
         interpret=interpret,
-    )(dleaf)
+    )(a)
+    return lo[:p], linv[:p]
 
 
 # ---------------------------------------------------------------------------

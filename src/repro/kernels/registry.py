@@ -204,6 +204,10 @@ DEFAULT_CONFIG = SolveConfig()
 # TPU core VMEM, leaving headroom for double buffering.
 _VMEM_BUDGET = 8 * 1024 * 1024
 
+# Most tiles one factoring program holds (leaf_factor / build_gram[_dist]):
+# enough independent panel chains to hide one chain's step latency.
+_MAX_TILES = 8
+
 
 @dataclasses.dataclass(frozen=True)
 class TileConfig:
@@ -211,6 +215,7 @@ class TileConfig:
 
     block_n0: int          # rows of the leaf block each program handles
     vmem_bytes: int        # working-set estimate at that tile size
+    tiles: int = 1         # whole tiles per program (factoring stages)
 
     @property
     def fits(self) -> bool:
@@ -267,7 +272,7 @@ def tile_config(stage: str, *, n0: int, r: int, k: int, d: int = 0,
     the leaf size for oos_local, the rank for oos_walk).  The query batch
     is padded to a block multiple by the ops wrapper, so no divisor snap.
 
-    Build stages: ``build_gram`` keeps a whole node per program (the (n0,
+    Build stages: ``build_gram`` keeps whole nodes per program (the (n0,
     n0) Gram tile is factorized in place, so it cannot row-tile; the
     returned config reports whether that working set fits).  ``build_cross``
     row-tiles the node block like the leaf stages: pts (bn, d) + parent
@@ -277,9 +282,24 @@ def tile_config(stage: str, *, n0: int, r: int, k: int, d: int = 0,
     gram + Cholesky (3 n0^2), ``build_cross_dist`` holds dist (bn, r) +
     Linv (r, r) + out (bn, r).  ``policy_dist`` (the landmark-policy inner
     loop) row-tiles like ``build_cross`` minus the Linv factor: pts (bn,
-    d) + centers (r, d) + dist out (bn, r).  ``leaf_factor`` factorizes the whole (n0,
-    n0) leaf Schur tile in place (dist-in, chol + inverse out: 3 n0^2).
-    ``leaf_update`` (the bordered rank-k extension) also processes whole
+    d) + centers (r, d) + dist out (bn, r).  ``leaf_factor`` factorizes
+    whole (n0, n0) leaf Schur tiles (SPD tile in, Cholesky + inverse out:
+    3 n0^2).
+
+    The factoring stages (``leaf_factor``, ``build_gram`` and
+    ``build_gram_dist`` when it factors) run the blocked in-VMEM Cholesky
+    and triangular inverse (``build_stage._cholesky_in_vmem``,
+    ``hck_leaf._tri_inv_in_vmem``): row panels of 8 rows (one float32
+    sublane group) factored by one-hot steps on the panel alone, the
+    off-diagonal work as MXU products; tiles of at most one panel, or not a
+    multiple of one, keep the unblocked one-hot loops.  Each panel step is
+    a short serial chain, so a program holds ``tiles`` whole tiles whose
+    chains interleave: the largest power of two up to ``_MAX_TILES`` whose
+    working set fits the budget, counting per tile the double-buffered
+    blocks above plus the factorization's three live (n0, n0) tiles
+    (complement, factor panels, transposed factor).  ``vmem_bytes`` is
+    that whole working set, so ``fits`` holds whenever one tile fits.
+    ``leaf_update`` (the bordered rank-k extension) processes whole
     leaves; here ``k`` is the number of appended rows, so the working set
     is 2 n0^2 + k n0 + k^2 in plus two (n0+k)^2 extended factors out.
 
@@ -293,18 +313,22 @@ def tile_config(stage: str, *, n0: int, r: int, k: int, d: int = 0,
         leaf_block = _autotuned_block(stage, n0=n0, r=r, k=k, d=d,
                                       itemsize=itemsize)
 
-    if stage in ("build_gram", "build_gram_dist", "leaf_factor",
-                 "leaf_update"):
-        if stage == "build_gram":
-            usage_g = (n0 * d + 2 * n0 * n0) * itemsize
-        elif stage == "leaf_update":
-            # old factors (2 n0^2) + cross/appended blocks (k n0 + k^2)
-            # + two extended (n0+k, n0+k) outputs, whole-leaf per program
-            usage_g = (2 * n0 * n0 + k * n0 + k * k
-                       + 2 * (n0 + k) * (n0 + k)) * itemsize
-        else:   # dist tile (or SPD tile) in, two (n0, n0) factors out
-            usage_g = 3 * n0 * n0 * itemsize
-        return TileConfig(n0, usage_g)
+    if stage == "leaf_update":
+        # old factors (2 n0^2) + cross/appended blocks (k n0 + k^2)
+        # + two extended (n0+k, n0+k) outputs, whole-leaf per program
+        return TileConfig(n0, (2 * n0 * n0 + k * n0 + k * k
+                               + 2 * (n0 + k) * (n0 + k)) * itemsize)
+
+    if stage in ("build_gram", "build_gram_dist", "leaf_factor"):
+        # per tile: the double-buffered blocks (input, gram and/or factor
+        # outputs) + the factorization's live tiles (complement, panels,
+        # transposed factor): 3 n0^2
+        io = 3 * n0 * n0 if stage != "build_gram" else n0 * d + 2 * n0 * n0
+        per_tile = (2 * io + 3 * n0 * n0) * itemsize
+        tiles = _MAX_TILES
+        while tiles > 1 and tiles * per_tile > _VMEM_BUDGET:
+            tiles //= 2
+        return TileConfig(n0, tiles * per_tile, tiles)
 
     if stage in ("build_cross", "build_cross_dist", "policy_dist"):
         def usage(bn: int) -> int:

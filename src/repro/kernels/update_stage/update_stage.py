@@ -3,9 +3,10 @@
 One program per leaf: the existing ``(n0, n0)`` Cholesky factor and its
 inverse stay resident in VMEM while the appended rows' cross block is
 triangular-solved (as a GEMM against ``linv``), the ``(k, k)`` Schur
-complement is formed, factored with the same in-VMEM one-hot Cholesky
-loop as ``build_gram``/``leaf_factor``, inverted by one-hot forward
-substitution, and both extended ``(n0+k, n0+k)`` factors are assembled
+complement is formed, factored and inverted by the same in-VMEM helpers
+as ``build_gram``/``leaf_factor`` (a k x k block smaller than one of their
+panels takes their unblocked one-hot loops), and both extended
+``(n0+k, n0+k)`` factors are assembled
 and written once — the update never re-reads or re-factors the old
 block, so its cost is O(k n0^2 + k^2 n0 + k^3) per leaf instead of the
 O(n0^3) full re-factorization.

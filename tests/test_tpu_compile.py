@@ -6,8 +6,12 @@ Each case compiles one stage's ``ops`` wrapper with ``interpret=False`` at
 the width of the YearPredictionMSD smoke (``chip_smoke.py``: 4096 leaves,
 n0 = r = 128, d = 90, one right-hand side) for one chip of a described
 ``v5e:2x2`` topology, and asserts the program holds the Mosaic kernel
-(``tpu_custom_call``) and fits the chip's memory.  A compile that passes
-is not a chip run: nothing executes here.
+(``tpu_custom_call``) and fits the chip's memory.  The factoring stages
+(``leaf_factor``, ``build_gram``) also compile at n0 = 256, each with the
+tiles per program that :func:`repro.kernels.registry.tile_config` picks
+there, and that pick must fit its VMEM budget (Mosaic itself refuses a
+kernel over the scoped VMEM limit).  A compile that passes is not a chip
+run: nothing executes here.
 
 The topology is described inside a fixture (never at import): only one
 process at a time may load the TPU library, and a decision taken while
@@ -20,6 +24,9 @@ import pytest
 
 LEAVES, N0, R, D, K, Q = 4096, 128, 128, 90, 1, 1024
 HBM_BYTES = 16 * 1024 ** 3          # one v5e chip
+# cases that factor several tiles per program -> their registry stage
+FACTORING = {"leaf_factor": "leaf_factor", "leaf_factor_256": "leaf_factor",
+             "build_gram": "build_gram", "build_gram_256": "build_gram"}
 
 
 @pytest.fixture(scope="module")
@@ -65,6 +72,8 @@ def _case(stage: str):
         # landmark Grams + Cholesky of the deepest level; leaf Grams
         "build_gram": (functools.partial(build.build_gram, jitter=1e-5,
                                          **gauss), [(pairs, R, D)]),
+        "build_gram_256": (functools.partial(build.build_gram, jitter=1e-5,
+                                             **gauss), [(pairs, 256, D)]),
         "build_gram_leaf": (functools.partial(build.build_gram, jitter=1e-5,
                                               want_chol=False, **gauss),
                             [(LEAVES, N0, D)]),
@@ -82,6 +91,10 @@ def _case(stage: str):
                         [(pairs, 2 * N0, D), (pairs, R, D)]),
         "leaf_factor": (functools.partial(leaf.leaf_factor, interpret=False),
                         [(LEAVES, N0, N0)]),
+        # the same leaf count of twice the width (a 256-point leaf config)
+        "leaf_factor_256": (functools.partial(leaf.leaf_factor,
+                                              interpret=False),
+                            [(LEAVES, 256, 256)]),
         "leaf_solve": (functools.partial(leaf.leaf_solve, interpret=False),
                        [(LEAVES, N0, N0), (LEAVES, N0, R), (LEAVES, R, R),
                         (LEAVES, N0, K)]),
@@ -105,8 +118,9 @@ def _case(stage: str):
 
 
 @pytest.mark.parametrize("stage", [
-    "build_gram", "build_gram_leaf", "build_cross", "build_gram_dist",
-    "build_cross_dist", "policy_dist", "leaf_factor", "leaf_solve",
+    "build_gram", "build_gram_256", "build_gram_leaf", "build_cross",
+    "build_gram_dist", "build_cross_dist", "policy_dist", "leaf_factor",
+    "leaf_factor_256", "leaf_solve",
     "leaf_matvec", "leaf_project", "leaf_update", "oos_local", "oos_walk",
     "kernel_matvec"])
 def test_stage_compiles_for_v5e(one_chip, stage):
@@ -122,3 +136,9 @@ def test_stage_compiles_for_v5e(one_chip, stage):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < HBM_BYTES, (stage, total)
+    if stage in FACTORING:
+        from repro.kernels.registry import tile_config
+
+        _, n0, d = shapes[0]
+        cfg = tile_config(FACTORING[stage], n0=n0, r=n0, k=1, d=d)
+        assert cfg.tiles > 1 and cfg.fits, (stage, cfg)
